@@ -28,6 +28,11 @@ budget) transparently take the object path per lane; `counters` records
 which path served each lane so tests and benches can assert the boundary.
 Lanes of *different* key sizes within one batch are grouped by channel
 count and each cohort runs as one kernel call.
+
+Exactness: the channels need exact float64 products and sums below 2^53,
+which only the CPU gives (`repro.kernels.exact_float64`; a TPU emulates
+float64).  Elsewhere every vectorized entry point raises `InexactDevice`
+instead of serving wrong integers.
 """
 
 from __future__ import annotations
@@ -41,10 +46,26 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.crypto import paillier as pai
+from repro.kernels import exact_float64
 from repro.kernels.bignum import ops, ref
 
 SCORE_WINDOW = 5    # 15-bit fixed-point scalars -> at most 3 window positions
 EXP_WINDOW = 4      # dense (key-sized) exponents: n for blinding, lambda
+
+
+class InexactDevice(RuntimeError):
+    """The default device cannot run the float64 channel arithmetic
+    exactly, so the vectorized tier refuses rather than serve wrong
+    integers."""
+
+
+def _require_exact() -> None:
+    if not exact_float64():
+        raise InexactDevice(
+            f"the {jax.default_backend()} device does not run the float64 "
+            f"RNS channels exactly; vectorized Paillier refuses to serve "
+            f"on it")
+
 
 # Which path served each lane-call: tests and the fallback-boundary bench
 # assert on these.  reset_counters() between measurements.
@@ -162,11 +183,12 @@ def encrypt_vector(pub: pai.PaillierPublicKey, e: np.ndarray,
     if not fits(pub) or len(e) == 0:
         counters["object"] += 1
         return pai.encrypt_vector(pub, e, rng)
+    _require_exact()
     counters["vectorized"] += 1
     ms = pai.encode_vector(e, pub.n)     # one batched call, not per-lane
     rs = [_draw_r(pub, rng) for _ in ms]
     ctx = _ctx(pub.n_sq)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         C = _consts([ctx], batch_ndim=2)
         base = _to_rns_mont([ctx], [rs])
         ndig = ops.to_digits([pub.n], EXP_WINDOW)
@@ -195,6 +217,8 @@ def encrypted_scores_batch(
     if rngs is None:
         rngs = [None] * nlanes
     out: List[Optional[list]] = [None] * nlanes
+    if any(fits(pub) for pub in pubs):
+        _require_exact()
 
     # Blinding must be drawn lane-by-lane in candidate order *before* any
     # cohort regrouping, to consume each lane's stream exactly as the
@@ -227,7 +251,7 @@ def encrypted_scores_batch(
                  for row, ctx in zip(qs, ctxs)]
         ndig = ops.to_digits([pubs[i].n for i in lanes], EXP_WINDOW)
         kprime = blk.shape[1]
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             res = _score_kernel(
                 _to_rns_mont(ctxs, qs),
                 _to_rns_mont(ctxs, qinvs),
@@ -250,6 +274,8 @@ def decrypt_scores_batch(sks: Sequence[pai.PaillierSecretKey],
     L-function + centered fixed-point decode on the host (bit-exact)."""
     nlanes = len(sks)
     out: List[Optional[np.ndarray]] = [None] * nlanes
+    if any(fits(sk.pub) and len(enc) for sk, enc in zip(sks, enc_lists)):
+        _require_exact()
     cohorts: dict = {}
     for i, sk in enumerate(sks):
         if not fits(sk.pub) or len(enc_lists[i]) == 0:
@@ -263,7 +289,7 @@ def decrypt_scores_batch(sks: Sequence[pai.PaillierSecretKey],
         ctxs = [_ctx(sks[i].pub.n_sq) for i in lanes]
         kprime = len(enc_lists[lanes[0]])
         ldig = ops.to_digits([sks[i].lam for i in lanes], EXP_WINDOW)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             res = np.asarray(_exp_kernel(
                 _to_rns_mont(ctxs, [enc_lists[i] for i in lanes]),
                 np.ascontiguousarray(np.broadcast_to(
@@ -279,6 +305,6 @@ def decrypt_scores_batch(sks: Sequence[pai.PaillierSecretKey],
     return out
 
 
-__all__ = ["fits", "encrypt_vector", "encrypted_scores_batch",
+__all__ = ["InexactDevice", "fits", "encrypt_vector", "encrypted_scores_batch",
            "decrypt_scores_batch", "counters", "reset_counters",
            "SCORE_WINDOW", "EXP_WINDOW"]

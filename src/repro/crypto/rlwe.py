@@ -62,6 +62,7 @@ from repro import obs
 
 from repro.crypto import modring
 from repro.crypto.modring import PrimeCtx
+from repro.kernels import resolve_use_pallas
 from repro.kernels.ntt import ops as ntt_ops
 from repro.kernels.ntt import ref as ntt_ref
 
@@ -622,6 +623,10 @@ class ShardedCandidateCache:
     prefetches: int = 0            # shard touches recorded via `prefetch`
     admit_enqueued: int = 0        # admissions handed to the admitter
     admit_dropped: int = 0         # admission requests dropped (queue full)
+    admit_failed: int = 0          # background copies that raised (e.g. a
+                                   # device allocation failure); next touch
+                                   # retries
+    last_admit_error: Optional[str] = None
     policy_deferrals: int = 0      # touches below admit_threshold (no admit)
 
     def __post_init__(self):
@@ -715,6 +720,8 @@ class ShardedCandidateCache:
                 "prefetches": self.prefetches,
                 "admit_enqueued": self.admit_enqueued,
                 "admit_dropped": self.admit_dropped,
+                "admit_failed": self.admit_failed,
+                "last_admit_error": self.last_admit_error,
                 "policy_deferrals": self.policy_deferrals,
                 "pending_admissions": pending,
                 "epoch": self.epoch,
@@ -852,19 +859,21 @@ class ShardedCandidateCache:
                 s, parent = self._queue.popleft()
             tracer = self.tracer
             t0 = tracer.clock() if tracer.enabled else 0.0
+            err = None
             try:
                 hook = self._admit_hook   # test seam: delay/observe the copy
                 if hook is not None:
                     hook(s)
                 arr = self._stage_copy(s)
                 jax.block_until_ready(arr)   # the copy, off-request-path
-            except Exception:             # noqa: BLE001 — a failed copy must
-                arr = None                # not strand flush()/later admits
+            except Exception as e:        # noqa: BLE001 — a failed copy must
+                arr, err = None, repr(e)  # not strand flush()/later admits
             swapped = False
             with self._cv:
                 self._inflight.discard(s)
-                if arr is None:
-                    pass                  # dropped; next touch retries
+                if arr is None:           # counted; next touch retries
+                    self.admit_failed += 1
+                    self.last_admit_error = err
                 elif s in self._resident:
                     self._resident.move_to_end(s)
                 elif self._fits_budget(s) and self.max_resident_bytes != 0:
@@ -1216,17 +1225,15 @@ def encrypted_scores_cached_batch(params: RlweParams,
     pad = num_ct * cpt - num_cands
     c0 = jnp.stack([q.c0 for q in q_cts])                 # (B, chunks, P, N)
     c1 = jnp.stack([q.c1 for q in q_cts])
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = resolve_use_pallas(use_pallas)
     if isinstance(cache, ShardedCandidateCache):
         g = cache.gather(ids)                 # (B, nc, chunks, P, N)
         all0, all1 = _gathered_scores(
-            c0, c1, g, cache.twiddles, params.ctxs, cpt, pad,
-            bool(use_pallas))
+            c0, c1, g, cache.twiddles, params.ctxs, cpt, pad, use_pallas)
     else:
         all0, all1 = _cached_scores(
             c0, c1, cache.polys, jnp.asarray(ids), cache.twiddles,
-            params.ctxs, cpt, pad, bool(use_pallas))
+            params.ctxs, cpt, pad, use_pallas)
     return ScoreCiphertextBatch(c0=all0, c1=all1, n_dim=cache.n_dim,
                                 num_cands=num_cands)
 
@@ -1315,9 +1322,7 @@ def encrypted_scores_batch_stacked(params: RlweParams,
     """
     c0 = jnp.stack([q.c0 for q in q_cts])  # (B, chunks, P, N)
     c1 = jnp.stack([q.c1 for q in q_cts])
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if not use_pallas:
+    if not resolve_use_pallas(use_pallas):
         all0, all1 = _scores_batch_ref(c0, c1, packed, params.ctxs)
         return ScoreCiphertextBatch(c0=all0, c1=all1, n_dim=n_dim,
                                     num_cands=num_cands)

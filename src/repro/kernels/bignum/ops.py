@@ -7,7 +7,7 @@ contractions against the fixed [s, s+1] matrices from `ref.RnsSystem`, which
 XLA CPU lowers to Eigen GEMMs; everything else is elementwise and fuses.
 
 All functions assume float64 inputs and MUST run (trace + execute) under
-``jax.experimental.enable_x64()`` — the caller owns that context.  Constants
+``jax.enable_x64(True)`` — the caller owns that context.  Constants
 travel in a plain dict pytree (see `make_consts`): system matrices are
 shared across lanes, per-modulus vectors (`c1`, `NMinv_t`, `one`) are
 stacked/broadcast by the caller to match the value batch shape, which is

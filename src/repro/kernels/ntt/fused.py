@@ -58,15 +58,16 @@ from repro.kernels.ntt import ntt as _ntt
 def _accumulate(polys_ref, tw_ref, f0_ref, f1_ref, *, q: int, mu: int,
                 cpt: int, chunks: int):
     """Shared kernel body: twiddle rotate -> Hadamard(c0, c1) -> raw-sum ->
-    one Barrett reduction.  Returns a (2, n) tile [acc0; acc1] in [0, q)."""
-    n = polys_ref.shape[-1]
-    g = polys_ref[...].reshape(cpt, chunks, n)
-    tw = tw_ref[...]                                    # (cpt, n)
-    f0 = f0_ref[...].reshape(chunks, n)
-    f1 = f1_ref[...].reshape(chunks, n)
-    rot = modring.mod_mul(g, tw[:, None, :], q, mu)     # slot twiddle rotate
-    p0 = modring.mod_mul(rot, f0[None], q, mu).reshape(cpt * chunks, n)
-    p1 = modring.mod_mul(rot, f1[None], q, mu).reshape(cpt * chunks, n)
+    one Barrett reduction.  Returns a (2, R, 128) tile [acc0; acc1] in
+    [0, q) (polynomials in the `ntt.plane` layout)."""
+    plane = polys_ref.shape[-2:]
+    g = polys_ref[...].reshape((cpt, chunks) + plane)
+    tw = tw_ref[...]                                    # (cpt, R, 128)
+    f0 = f0_ref[...].reshape((1, chunks) + plane)
+    f1 = f1_ref[...].reshape((1, chunks) + plane)
+    rot = modring.mod_mul(g, tw[:, None], q, mu)        # slot twiddle rotate
+    p0 = modring.mod_mul(rot, f0, q, mu).reshape((cpt * chunks,) + plane)
+    p1 = modring.mod_mul(rot, f1, q, mu).reshape((cpt * chunks,) + plane)
     return jnp.stack([
         modring.barrett_reduce(jnp.sum(p0, axis=0), q, mu),
         modring.barrett_reduce(jnp.sum(p1, axis=0), q, mu)])
@@ -74,25 +75,58 @@ def _accumulate(polys_ref, tw_ref, f0_ref, f1_ref, *, q: int, mu: int,
 
 def _fused_kernel(polys_ref, tw_ref, f0_ref, f1_ref, o0_ref, o1_ref, *,
                   q: int, mu: int, cpt: int, chunks: int):
-    n = polys_ref.shape[-1]
     acc = _accumulate(polys_ref, tw_ref, f0_ref, f1_ref, q=q, mu=mu,
                       cpt=cpt, chunks=chunks)
-    o0_ref[...] = acc[0].reshape(1, 1, n)
-    o1_ref[...] = acc[1].reshape(1, 1, n)
+    o0_ref[...] = acc[0:1][None]
+    o1_ref[...] = acc[1:2][None]
 
 
-def _fused_intt_kernel(polys_ref, tw_ref, f0_ref, f1_ref, ipsi_ref,
+def _fused_intt_kernel(polys_ref, tw_ref, f0_ref, f1_ref, itw_ref,
                        o0_ref, o1_ref, *, q: int, mu: int, cpt: int,
                        chunks: int, n_inv: int):
-    n = polys_ref.shape[-1]
     acc = _accumulate(polys_ref, tw_ref, f0_ref, f1_ref, q=q, mu=mu,
                       cpt=cpt, chunks=chunks)
-    # absorb the inverse NTT: the (2, n) accumulator tile runs the exact
-    # butterfly network of the standalone kernel while still VMEM-resident
-    out = _ntt.inv_butterflies(acc, ipsi_ref[...], q=q, mu=mu, n=n,
-                               n_inv=n_inv)
-    o0_ref[...] = out[0].reshape(1, 1, n)
-    o1_ref[...] = out[1].reshape(1, 1, n)
+    # absorb the inverse NTT: the (2, R, 128) accumulator tile runs the
+    # exact butterfly network of the standalone kernel while still
+    # VMEM-resident
+    out = _ntt.inv_butterflies(acc, itw_ref[...], q=q, mu=mu, n_inv=n_inv)
+    o0_ref[...] = out[0:1][None]
+    o1_ref[...] = out[1:2][None]
+
+
+def _rerank_call(kern, name: str, polys, tw, f0, f1, consts,
+                 ctx: PrimeCtx, interpret: bool):
+    """One grid cell per (batch lane, result ciphertext); every operand in
+    the (R, 128) plane layout, so each block's last two dims are a whole
+    plane — legal TPU tiling for any num_ct, rows and chunks."""
+    bsz, num_ct, rows, n = polys.shape
+    cpt, chunks = tw.shape[0], f0.shape[1]
+    assert rows == cpt * chunks, (rows, cpt, chunks)
+    assert n == ctx.n and f0.shape == f1.shape == (bsz, chunks, n)
+    assert rows * (ctx.q - 1) < 2**31, "int32 accumulator would wrap"
+    pl_shape = _ntt.plane(n)
+    out = jax.ShapeDtypeStruct((bsz, num_ct) + pl_shape, jnp.int32)
+    out_spec = pl.BlockSpec((1, 1) + pl_shape, lambda b, t: (b, t, 0, 0))
+    query = pl.BlockSpec((1, chunks) + pl_shape, lambda b, t: (b, 0, 0, 0))
+    acc0, acc1 = pl.pallas_call(
+        kern,
+        grid=(bsz, num_ct),
+        in_specs=[
+            pl.BlockSpec((1, 1, rows) + pl_shape,
+                         lambda b, t: (b, t, 0, 0, 0)),
+            pl.BlockSpec((cpt,) + pl_shape, lambda b, t: (0, 0, 0)),
+            query, query,
+        ] + [pl.BlockSpec(c.shape, lambda b, t, nd=c.ndim: (0,) * nd)
+             for c in consts],
+        out_specs=[out_spec, out_spec],
+        out_shape=[out, out],
+        interpret=interpret,
+        name=name,
+    )(polys.reshape((bsz, num_ct, rows) + pl_shape),
+      tw.reshape((cpt,) + pl_shape),
+      f0.reshape((bsz, chunks) + pl_shape),
+      f1.reshape((bsz, chunks) + pl_shape), *consts)
+    return acc0.reshape(bsz, num_ct, n), acc1.reshape(bsz, num_ct, n)
 
 
 @functools.partial(jax.jit, static_argnames=("ctx", "interpret"))
@@ -104,28 +138,10 @@ def fused_rerank_pallas(polys, tw, f0, f1, ctx: PrimeCtx, *,
     tw: (cpt, N) monomial twiddles; f0/f1: (B, chunks, N) query NTTs.
     Returns (acc0, acc1), each (B, num_ct, N) int32 in [0, q).
     """
-    bsz, num_ct, rows, n = polys.shape
-    cpt, chunks = tw.shape[0], f0.shape[1]
-    assert rows == cpt * chunks, (rows, cpt, chunks)
-    assert n == ctx.n and f0.shape == f1.shape == (bsz, chunks, n)
-    assert rows * (ctx.q - 1) < 2**31, "int32 accumulator would wrap"
     kern = functools.partial(_fused_kernel, q=ctx.q, mu=ctx.mu,
-                             cpt=cpt, chunks=chunks)
-    out = jax.ShapeDtypeStruct((bsz, num_ct, n), jnp.int32)
-    return pl.pallas_call(
-        kern,
-        grid=(bsz, num_ct),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, n), lambda b, t: (b, t, 0, 0)),
-            pl.BlockSpec((cpt, n), lambda b, t: (0, 0)),
-            pl.BlockSpec((1, chunks, n), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, chunks, n), lambda b, t: (b, 0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, 1, n), lambda b, t: (b, t, 0)),
-                   pl.BlockSpec((1, 1, n), lambda b, t: (b, t, 0))],
-        out_shape=[out, out],
-        interpret=interpret,
-    )(polys, tw, f0, f1)
+                             cpt=tw.shape[0], chunks=f0.shape[1])
+    return _rerank_call(kern, "rerank_fused", polys, tw, f0, f1, (), ctx,
+                        interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("ctx", "interpret"))
@@ -136,34 +152,16 @@ def fused_rerank_intt_pallas(polys, tw, f0, f1, ctx: PrimeCtx, *,
 
     Same contract as `fused_rerank_pallas` but the returned (acc0, acc1)
     are in the *coefficient* domain: each grid cell's accumulator pair is
-    inverse-NTT'd as a (2, N) tile before it leaves VMEM (the exact
+    inverse-NTT'd as a (2, R, 128) tile before it leaves VMEM (the exact
     `inv_butterflies` network of `ntt_pallas`, so outputs are bit-identical
     to fused_rerank_pallas followed by the standalone inverse NTT).
     """
-    bsz, num_ct, rows, n = polys.shape
-    cpt, chunks = tw.shape[0], f0.shape[1]
-    assert rows == cpt * chunks, (rows, cpt, chunks)
-    assert n == ctx.n and f0.shape == f1.shape == (bsz, chunks, n)
-    assert rows * (ctx.q - 1) < 2**31, "int32 accumulator would wrap"
     kern = functools.partial(_fused_intt_kernel, q=ctx.q, mu=ctx.mu,
-                             cpt=cpt, chunks=chunks, n_inv=ctx.n_inv)
-    out = jax.ShapeDtypeStruct((bsz, num_ct, n), jnp.int32)
-    ipsi = jnp.asarray(ctx.ipsi_table)
-    return pl.pallas_call(
-        kern,
-        grid=(bsz, num_ct),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, n), lambda b, t: (b, t, 0, 0)),
-            pl.BlockSpec((cpt, n), lambda b, t: (0, 0)),
-            pl.BlockSpec((1, chunks, n), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, chunks, n), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((n,), lambda b, t: (0,)),
-        ],
-        out_specs=[pl.BlockSpec((1, 1, n), lambda b, t: (b, t, 0)),
-                   pl.BlockSpec((1, 1, n), lambda b, t: (b, t, 0))],
-        out_shape=[out, out],
-        interpret=interpret,
-    )(polys, tw, f0, f1, ipsi)
+                             cpt=tw.shape[0], chunks=f0.shape[1],
+                             n_inv=ctx.n_inv)
+    itw = jnp.asarray(_ntt.stage_twiddles(ctx, True))
+    return _rerank_call(kern, "rerank_fused_intt", polys, tw, f0, f1,
+                        (itw,), ctx, interpret)
 
 
 __all__ = ["fused_rerank_pallas", "fused_rerank_intt_pallas"]

@@ -1,7 +1,9 @@
 """Public jit'd API over the NTT kernel with an XLA fallback.
 
 ``use_pallas`` selects the Pallas kernel (interpret-mode on CPU, compiled on
-TPU); the fallback is the pure-jnp reference, which XLA fuses reasonably but
+TPU, refused elsewhere — see `repro.kernels`; ``None`` picks it on a TPU
+only, and tests pass ``True`` to run the kernel in interpret mode); the
+fallback is the pure-jnp reference, which XLA fuses reasonably but
 round-trips HBM between stages on real hardware.
 """
 
@@ -13,13 +15,11 @@ import jax
 
 from repro.crypto import modring
 from repro.crypto.modring import PrimeCtx
+from repro.kernels import interpret_mode as _interpret
+from repro.kernels import resolve_use_pallas as _resolve
 from repro.kernels.ntt import fused as _fused
 from repro.kernels.ntt import ntt as _kern
 from repro.kernels.ntt import ref as _ref
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # PrimeCtx instances are interned per (q, n) and hash by identity, so they
@@ -59,14 +59,6 @@ def _fused_rotate_hadamard_ref(polys, tw, f0, f1, ctx: PrimeCtx):
 def _fused_rotate_hadamard_intt_ref(polys, tw, f0, f1, ctx: PrimeCtx):
     acc0, acc1 = _fused_rotate_hadamard_ref(polys, tw, f0, f1, ctx)
     return _ref.ntt_inv_ref(acc0, ctx), _ref.ntt_inv_ref(acc1, ctx)
-
-
-def _resolve(use_pallas):
-    """None -> auto: Pallas on TPU, XLA reference path elsewhere (tests pass
-    use_pallas=True explicitly to exercise the kernel in interpret mode)."""
-    if use_pallas is None:
-        return jax.default_backend() == "tpu"
-    return use_pallas
 
 
 def ntt_fwd(x, ctx: PrimeCtx, *, use_pallas=None):

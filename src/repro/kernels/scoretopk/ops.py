@@ -2,11 +2,13 @@
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode, resolve_use_pallas
 from repro.kernels.scoretopk import ref as _ref
 from repro.kernels.scoretopk import scoretopk as _kern
 
@@ -17,39 +19,35 @@ class TopK(NamedTuple):
     exact: jax.Array    # () bool — certificate that the result is exact
 
 
-def _resolve(use_pallas):
-    if use_pallas is None:
-        return jax.default_backend() == "tpu"
-    return use_pallas
-
-
-def topk_scores(queries, corpus, k: int, *, tile: int = 2048,
+def topk_scores(queries, corpus, k: int, *, tile: int | None = None,
                 per_tile_k: int | None = None, use_pallas=None) -> TopK:
     """Exact top-k inner-product search.
 
-    ``per_tile_k`` < k trades selection work for a (checked) exactness
-    certificate: the merged result is exact iff no tile contributed all of its
-    per-tile candidates.  Default per_tile_k = min(k, tile) which is always
-    exact.
+    ``tile`` (corpus rows per kernel step) defaults to
+    `scoretopk.corpus_tile` of the embedding width, capped at the corpus
+    size.  ``per_tile_k`` < k trades selection work for a (checked)
+    exactness certificate: the merged result is exact iff no tile
+    contributed all of its per-tile candidates.  Default per_tile_k =
+    min(k, tile) which is always exact.
     """
-    use_pallas = _resolve(use_pallas)
-    b = queries.shape[0]
-    n_rows = corpus.shape[0]
+    n_rows, n = corpus.shape
+    tile = min(_kern.corpus_tile(n) if tile is None else tile, n_rows)
     k = min(k, n_rows)
-    kk = min(per_tile_k or k, k, tile, n_rows)
-    if n_rows <= tile or not use_pallas:
-        if use_pallas:
-            vals, gidx = _kern.score_topk_pallas(
-                queries, corpus, kk=min(kk, n_rows), tile=min(tile, n_rows),
-                interpret=jax.default_backend() != "tpu")
-        else:
-            vals, gidx = _ref.tile_topk_ref(queries, corpus, kk, tile)
-        mv, mi = _ref.merge_tiles_ref(vals, gidx, k)
-        exact = _certificate(gidx, mi, kk) if kk < k else jnp.asarray(True)
-        return TopK(mv, mi, exact)
-    vals, gidx = _kern.score_topk_pallas(
-        queries, corpus, kk=kk, tile=tile,
-        interpret=jax.default_backend() != "tpu")
+    kk = min(per_tile_k or k, k, tile)
+    pallas = resolve_use_pallas(use_pallas)
+    return _topk(queries, corpus, k=k, kk=kk, tile=tile, pallas=pallas,
+                 interpret=pallas and interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("k", "kk", "tile", "pallas",
+                                             "interpret"))
+def _topk(queries, corpus, *, k, kk, tile, pallas, interpret) -> TopK:
+    """Scan, merge and certificate as one program (one dispatch)."""
+    if pallas:
+        vals, gidx = _kern.score_topk_pallas(
+            queries, corpus, kk=kk, tile=tile, interpret=interpret)
+    else:
+        vals, gidx = _ref.tile_topk_ref(queries, corpus, kk, tile)
     mv, mi = _ref.merge_tiles_ref(vals, gidx, k)
     exact = _certificate(gidx, mi, kk) if kk < k else jnp.asarray(True)
     return TopK(mv, mi, exact)

@@ -133,9 +133,6 @@ def moe_fwd_sharded(p, x, spec: MoeSpec):
     (exactly a dense-TP all-reduce).  Replaces the einsum formulation's
     gather/scatter all-reduces of (B, E, C, d) buffers (~16x the bytes).
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     mesh, ep = spec.mesh, spec.ep_axis
     ba = spec.batch_axes or ()
     e = spec.padded_experts
@@ -167,10 +164,10 @@ def moe_fwd_sharded(p, x, spec: MoeSpec):
                                 my * e_loc, e_loc, cap, spec)
         return jax.lax.psum(out, ep)
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(w_spec, tok_spec, route_spec, route_spec),
-        out_specs=tok_spec, check_rep=False,
+        out_specs=tok_spec, check_vma=False,
     )({k: p[k] for k in ("w_gate", "w_up", "w_down")}, x, gate_w, gate_i)
     return out, aux
 

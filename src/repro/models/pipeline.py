@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -75,10 +74,10 @@ def pipeline_apply(stage_params, x_micro, stage_fn: Callable, *, mesh,
     # this schedule trips XLA's PartitionId/manual-subgroup limitations on the
     # pinned jax version, so non-pipeline axes are handled by `inner_specs`
     # instead (shard the microbatch dim there; unmentioned axes replicate).
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(in_leaf_spec, inner_specs),
-        out_specs=inner_specs, check_rep=False,
+        out_specs=inner_specs, check_vma=False,
     )(stage_params, x_micro)
 
 

@@ -89,8 +89,7 @@ class TransformerConfig:
     def _constrain(self, x, *parts):
         if self.batch_axes is None:
             return x
-        from jax.sharding import PartitionSpec as _P
-        return jax.lax.with_sharding_constraint(x, _P(*parts))
+        return jax.lax.with_sharding_constraint(x, P(*parts))
 
     @property
     def is_moe(self) -> bool:

@@ -1,9 +1,10 @@
 """Distributed exact top-k' search over a sharded FlatIndex.
 
 Per-device: the fused Pallas score+select kernel reduces the local shard to
-(B, k_local) candidates.  Cross-device: shards are stacked along a leading
-axis (shard_map out_spec), and a tiny replicated top-k merge runs outside.
-Collective bytes scale with devices * B * k (KB), never with N.
+(B, k_local) candidates.  Cross-device: every shard all-gathers the others'
+candidates and runs the same tiny top-k merge, so the result leaves the
+shard_map replicated — on Auto and Explicit mesh axes alike.  Collective
+bytes scale with devices * B * k (KB), never with N.
 """
 
 from __future__ import annotations
@@ -13,7 +14,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 import numpy as np
@@ -28,7 +28,8 @@ class SearchResult(NamedTuple):
     exact: jax.Array     # () bool
 
 
-def make_sharded_topk(mesh, axes, n_rows: int, k: int, *, tile: int = 2048,
+def make_sharded_topk(mesh, axes, n_rows: int, k: int, *,
+                      tile: Optional[int] = None,
                       per_tile_k: Optional[int] = None, use_pallas=None):
     """Functional core: (queries, corpus) -> SearchResult, jit/lower-able.
 
@@ -39,38 +40,42 @@ def make_sharded_topk(mesh, axes, n_rows: int, k: int, *, tile: int = 2048,
         n_shards *= mesh.shape[a]
     rows_local = n_rows // n_shards
     k_local = min(k, rows_local)
+    k_eff = min(k, n_shards * k_local)
 
     def local_search(q, shard):
         # linearized shard position over the row axes
         pos = jnp.int32(0)
         for a in axes:
             pos = pos * mesh.shape[a] + jax.lax.axis_index(a)
-        out = sops.topk_scores(q, shard, k_local, tile=min(tile, rows_local),
+        out = sops.topk_scores(q, shard, k_local, tile=tile,
                                per_tile_k=per_tile_k, use_pallas=use_pallas)
         gidx = out.indices + pos * rows_local
-        return (out.values[None], gidx[None],
-                out.exact.reshape(1)[None])
+        # (n_shards, B, k_local) in shard order on every device, then the
+        # merge: shard-major ties -> lower global id first, as one scan
+        vals = jax.lax.all_gather(out.values, axes)
+        ids = jax.lax.all_gather(gidx, axes)
+        ok = jax.lax.all_gather(out.exact, axes)
+        b = q.shape[0]
+        flat_v = jnp.swapaxes(vals, 0, 1).reshape(b, n_shards * k_local)
+        flat_i = jnp.swapaxes(ids, 0, 1).reshape(b, n_shards * k_local)
+        mv, mpos = jax.lax.top_k(flat_v, k_eff)
+        return mv, jnp.take_along_axis(flat_i, mpos, axis=1), jnp.all(ok)
 
     def search(queries, corpus):
-        stacked_v, stacked_i, stacked_ok = shard_map(
+        mv, mi, ok = jax.shard_map(
             local_search, mesh=mesh,
             in_specs=(P(), P(axes, None)),
-            out_specs=(P(axes), P(axes), P(axes)),
-            check_rep=False,
+            out_specs=(P(), P(), P()),
+            check_vma=False,
         )(queries, corpus)
-        b = queries.shape[0]
-        flat_v = jnp.swapaxes(stacked_v, 0, 1).reshape(b, n_shards * k_local)
-        flat_i = jnp.swapaxes(stacked_i, 0, 1).reshape(b, n_shards * k_local)
-        k_eff = min(k, n_shards * k_local)
-        mv, mpos = jax.lax.top_k(flat_v, k_eff)
-        mi = jnp.take_along_axis(flat_i, mpos, axis=1)
-        return SearchResult(mv, mi, jnp.all(stacked_ok))
+        return SearchResult(mv, mi, ok)
 
     return search
 
 
 def distributed_topk(index: FlatIndex, queries, k: int, *,
-                     tile: int = 2048, per_tile_k: Optional[int] = None,
+                     tile: Optional[int] = None,
+                     per_tile_k: Optional[int] = None,
                      use_pallas=None) -> SearchResult:
     """Exact top-k of <query, corpus row> over the (possibly sharded) index."""
     n_rows = index.num_rows  # includes shard padding
@@ -84,7 +89,8 @@ def distributed_topk(index: FlatIndex, queries, k: int, *,
     return search(queries, index.embeddings)
 
 
-def slice_topk(sl: IndexSlice, queries, k: int, *, tile: int = 2048,
+def slice_topk(sl: IndexSlice, queries, k: int, *,
+               tile: Optional[int] = None,
                per_tile_k: Optional[int] = None,
                use_pallas=None) -> SearchResult:
     """Exact top-k over one replica's row slice, in *global* ids.
@@ -96,8 +102,7 @@ def slice_topk(sl: IndexSlice, queries, k: int, *, tile: int = 2048,
     the invariant the scale-out router's differential harness pins.
     """
     k_local = min(k, sl.num_rows)
-    out = sops.topk_scores(queries, sl.embeddings, k_local,
-                           tile=min(tile, sl.num_rows),
+    out = sops.topk_scores(queries, sl.embeddings, k_local, tile=tile,
                            per_tile_k=per_tile_k, use_pallas=use_pallas)
     return SearchResult(out.values, out.indices + sl.start, out.exact)
 
@@ -122,7 +127,8 @@ def plan_nprobe(cluster_map, kprime: int, *, slack: float = 4.0) -> int:
 
 
 def cluster_topk(view, queries, k: int, *, nprobe: Optional[int] = None,
-                 tile: int = 2048, per_tile_k: Optional[int] = None,
+                 tile: Optional[int] = None,
+                 per_tile_k: Optional[int] = None,
                  use_pallas=None) -> SearchResult:
     """IVF first-stage routed top-k over a `CorpusView` (or any object
     with ``cluster_map`` + ``cluster_slice``).
@@ -144,9 +150,11 @@ def cluster_topk(view, queries, k: int, *, nprobe: Optional[int] = None,
     num_clusters = cm.num_clusters
     probe = num_clusters if nprobe is None else max(1, min(int(nprobe),
                                                            num_clusters))
-    queries = jnp.asarray(queries, jnp.float32)
+    # routing and the per-cluster query selection stay on the host: a
+    # device gather per cluster costs more than the cluster's scan
+    queries = np.asarray(queries, np.float32)
     bsz = queries.shape[0]
-    routed = cm.route(np.asarray(queries), probe)            # (B, probe)
+    routed = cm.route(queries, probe)                        # (B, probe)
     if np.min(cm.sizes[routed].sum(axis=1)) < k:
         raise ValueError(
             f"nprobe={probe} routes fewer than k={k} rows; raise nprobe")

@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -76,8 +75,8 @@ def make_compressed_psum(mesh, axes: tuple):
                 acc = jax.lax.psum(q.astype(jnp.int32), axes)
                 return acc.astype(jnp.float32) * s_max
 
-            return shard_map(inner, mesh=mesh, in_specs=spec,
-                             out_specs=spec, check_rep=False)(g)
+            return jax.shard_map(inner, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False)(g)
 
         return jax.tree.map(leaf_psum, grads)
 

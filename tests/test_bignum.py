@@ -113,7 +113,7 @@ def test_ops_mont_mul_matches_ref(ctx):
     b = _rand_ints(rng, ctx.modulus, 5)
     am = ref.to_rns(ctx, [ref.to_mont(ctx, x) for x in a])
     bm = ref.to_rns(ctx, [ref.to_mont(ctx, y) for y in b])
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         C = ops.make_consts(ctx.system, [ctx], batch_ndim=2)
         got = np.asarray(ops.mont_mul(am[None], bm[None], C))[0]
     want = ref.from_rns(ctx, ref.mont_mul(ctx, am, bm))
@@ -127,7 +127,7 @@ def test_ops_windowed_exp_matches_python_pow(ctx):
     window = 4
     base = ref.to_rns(ctx, [ref.to_mont(ctx, x) for x in bases])[None]
     digits = ops.to_digits(exps, window)[None]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         C = ops.make_consts(ctx.system, [ctx], batch_ndim=2)
         table = ops.pow_table(base, C, window)
         got = np.asarray(ops.mont_exp_digits(table, digits, C, window))[0]
@@ -140,7 +140,7 @@ def test_ops_product_reduce_matches_python(ctx, count):
     rng = np.random.default_rng(8 + count)
     xs = _rand_ints(rng, ctx.modulus, count)
     vec = ref.to_rns(ctx, [ref.to_mont(ctx, x) for x in xs])
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         C = ops.make_consts(ctx.system, [ctx], batch_ndim=2)
         # product_reduce folds over axis -2; a [count, width] leaf block
         got = np.asarray(ops.product_reduce(vec[None], C))[0]

@@ -4,6 +4,7 @@ backend errors, wire parity against the object path under deterministic
 seeds, bit-exact batched decryption, and the oversized-key object
 fallback."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -159,6 +160,44 @@ def test_fallback_wire_parity_under_seeds():
                 rngs=[np.random.default_rng(11)])[0]
             == pai.encrypted_scores(big.pub, enc, cands[0],
                                     rng=np.random.default_rng(11)))
+
+
+# -- exactness of the float64 channels on the device ------------------------
+
+
+def test_float64_rule_admits_cpu_only(monkeypatch):
+    """The vectorized tier runs where doubles are native (the CPU) and
+    nowhere else: a TPU emulates float64."""
+    from repro import kernels
+    assert kernels.exact_float64()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not kernels.exact_float64()
+
+
+def test_inexact_device_refuses_vectorized_tier(monkeypatch):
+    """On an inexact device every vectorized entry point raises the typed
+    error before touching the lane's rng — never wrong integers — while
+    lanes on the object path (oversized keys) still serve exactly."""
+    monkeypatch.setattr(pvec, "exact_float64", lambda: False)
+    small = _keys(1)[0]
+    big = pai.keygen(1024, rng=np.random.default_rng(0))
+    rng = np.random.default_rng(3)
+    e = _unit(rng, DIM)
+    cands = [_unit(rng, KPRIME, DIM)]
+    enc = pai.encrypt_vector(small.pub, e, rng=np.random.default_rng(4))
+    cts = [pai.encrypted_scores(small.pub, enc, cands[0])]
+
+    draws = np.random.default_rng(9)
+    with pytest.raises(pvec.InexactDevice):
+        pvec.encrypt_vector(small.pub, e, rng=draws)
+    assert draws.bit_generator.state == \
+        np.random.default_rng(9).bit_generator.state
+    with pytest.raises(pvec.InexactDevice):
+        pvec.encrypted_scores_batch([small.pub], [enc], cands)
+    with pytest.raises(pvec.InexactDevice):
+        pvec.decrypt_scores_batch([small], cts)
+    assert (pvec.encrypt_vector(big.pub, e, rng=np.random.default_rng(8))
+            == pai.encrypt_vector(big.pub, e, rng=np.random.default_rng(8)))
 
 
 # -- backend objects drive the protocol symmetrically -----------------------

@@ -112,3 +112,22 @@ def test_kernel_leading_dims():
     got = np.asarray(ops.ntt_fwd(x, ctx))
     want = np.asarray(ops.ntt_fwd(x.reshape(15, 256), ctx)).reshape(3, 5, 256)
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# platform rule: compiled on a TPU, interpreted on the CPU, refused elsewhere
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_pallas_platform_rule(monkeypatch, platform, interpret):
+    import repro.kernels as kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: platform)
+    assert kernels.resolve_use_pallas(None) is (platform == "tpu")
+    assert kernels.resolve_use_pallas(False) is False
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="use_pallas=False"):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is interpret

@@ -94,6 +94,32 @@ def test_small_corpus_single_tile():
     np.testing.assert_array_equal(np.asarray(out.indices), np.asarray(want_i))
 
 
+def test_reference_scores_are_shape_independent():
+    """Every reference score is a pure function of its (query, row) pair:
+    a solo query, a sub-batch or a corpus slice reproduces the full
+    scan's bits, so exact ties stay ties across the router's slices."""
+    rng = np.random.default_rng(7)
+    q, e = _data(rng, 11, 1500, 64)
+    full = np.asarray(ref.score_ref(q, e))
+    for (b0, b1), (r0, r1) in [((3, 4), (0, 1500)), ((0, 8), (750, 1500)),
+                               ((2, 11), (375, 1125)), ((5, 6), (1499, 1500))]:
+        np.testing.assert_array_equal(
+            np.asarray(ref.score_ref(q[b0:b1], e[r0:r1])),
+            full[b0:b1, r0:r1])
+
+
+def test_reference_scan_scratch_is_linear():
+    """The XLA reference scan (the `use_pallas=False` path, the default on
+    the CPU) is one jitted program with O(B * N) scratch: no (B, N, n)
+    product is materialized (245 MB here, 24.6 GB at 10^6 x 768)."""
+    b, rows, n = 8, 10_000, 768
+    lowered = ref.tile_topk_ref.lower(
+        jax.ShapeDtypeStruct((b, n), jnp.float32),
+        jax.ShapeDtypeStruct((rows, n), jnp.float32), 154, 1024)
+    assert lowered.compile().memory_analysis().temp_size_in_bytes \
+        < 64 * b * rows
+
+
 def test_k_exceeds_corpus():
     rng = np.random.default_rng(6)
     q, e = _data(rng, 1, 17, 16)

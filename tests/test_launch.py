@@ -65,3 +65,62 @@ def test_roofline_model_flops_sane():
     f_moe = model_flops("qwen3-moe-30b-a3b", "train_4k")
     f_if_dense = 6 * 30e9 * 256 * 4096
     assert f_moe < 0.3 * f_if_dense
+
+
+# -- the serve entry point: exit status and compile-cache location ----------
+
+
+def test_serve_main_exits_nonzero_on_request_errors(monkeypatch):
+    """`main()` fails the process when a request ends in an error; an
+    admission shed is reported but is not an error."""
+    from types import SimpleNamespace
+
+    from repro.launch import serve as launch
+
+    def fake_serve(results):
+        return lambda args: launch.ServeReport(
+            plan=None, results=results, queries={}, summary={})
+
+    ok = SimpleNamespace(ok=True, shed_reason=None)
+    shed = SimpleNamespace(ok=False, shed_reason="deadline")
+    failed = SimpleNamespace(ok=False, shed_reason=None)
+    monkeypatch.setattr(launch, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(launch, "serve", fake_serve([ok, shed]))
+    assert launch.main([]) == 0
+    monkeypatch.setattr(launch, "serve", fake_serve([ok, failed]))
+    assert launch.main([]) == 1
+
+
+@pytest.mark.parametrize("from_env", [True, False],
+                         ids=["env_dir", "repo_root"])
+def test_compile_cache_location(tmp_path, from_env):
+    """`enable_compile_cache` writes to $JAX_COMPILATION_CACHE_DIR when it
+    is set, else to `.jax_cache/` at the repo root (here a stand-in root);
+    run in a child process so this test process keeps no cache."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = (
+        "import sys, pathlib, jax\n"
+        "from repro.launch import serve\n"
+        "serve.REPO_ROOT = pathlib.Path(sys.argv[1])\n"
+        "print(serve.enable_compile_cache())\n"
+        "jax.jit(lambda x: x * 2 + 1)(jax.numpy.arange(3))"
+        ".block_until_ready()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [str(src), os.environ.get("PYTHONPATH", "")]))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = tmp_path / ("env_cache" if from_env else ".jax_cache")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    out = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == str(want)
+    assert any(want.iterdir())
+    assert (tmp_path / ".jax_cache").exists() == (not from_env)

@@ -16,7 +16,10 @@ sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from repro.models import moe
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+# the einsum MoE is written for GSPMD's Auto sharding (bare-spec
+# with_sharding_constraint); jax.make_mesh defaults to Explicit axes
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 spec_e = moe.MoeSpec(d_model=32, d_ff=16, n_experts=8, top_k=2,
                      ep_pad_to=4, batch_axes=("data",), ep_axis="model")
 spec_s = moe.MoeSpec(d_model=32, d_ff=16, n_experts=8, top_k=2,

@@ -68,7 +68,10 @@ sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from repro.models import transformer as tf
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+# the transformer scaffold is written for GSPMD's Auto sharding (bare-spec
+# with_sharding_constraint); jax.make_mesh defaults to Explicit axes
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 cfg = tf.TransformerConfig(name="t", n_layers=4, d_model=64, n_heads=4,
                            n_kv_heads=2, d_ff=128, vocab=512, d_head=16,
                            dtype="float32", remat=False, kv_chunk=32,

@@ -474,6 +474,29 @@ def test_admit_queue_bounded_drops_are_counted(setup):
     assert len(sh.resident_shards) == 5
 
 
+def test_failed_admission_copy_is_counted(setup):
+    """A background copy that raises (on a device: an HBM allocation
+    failure) is counted with its error, leaves nothing resident, and the
+    next touch retries it."""
+    n_dim, docs, dense, _ = setup
+    sh = _sharded(dense, admit_threshold=1)
+    fail = [True]
+
+    def hook(_s):
+        if fail[0]:
+            raise MemoryError("RESOURCE_EXHAUSTED: shard copy")
+    sh._admit_hook = hook
+    sh.gather(np.array([[0]]))
+    sh.flush()
+    st = sh.stats()
+    assert st["admit_failed"] == 1 and sh.resident_shards == ()
+    assert "RESOURCE_EXHAUSTED" in st["last_admit_error"]
+    fail[0] = False
+    sh.gather(np.array([[0]]))
+    sh.flush()
+    assert sh.resident_shards == (0,) and sh.stats()["admit_failed"] == 1
+
+
 def test_prefetch_counts_touch_once_and_overlaps(setup):
     """A prefetch records the touch; the request's own gather of the same
     ids must not double-count it (otherwise every request would hit the
