@@ -143,77 +143,6 @@ def _serve_fault_section(params, rng) -> dict:
     return section
 
 
-def _stage_breakdown_section(params, rng) -> dict:
-    """Where a served request's time goes, stage by stage: one traced
-    engine stream (sharded cache, so admission/gather show up) emits the
-    repro.obs per-stage histograms into the bench payload — future PRs
-    (Paillier limb batching, ANN routing, TPU kernels) can prove which
-    stage they moved instead of pointing at an end-to-end number.  An
-    untraced pass of the same stream runs first (also the jit warmup), so
-    the traced/untraced wall ratio documents the enabled-tracing cost;
-    ``stage_coverage`` (sum of stage durations / dispatch duration) is
-    CI-gated to stay in [0.5, 1.05] — the timeline must keep accounting
-    for the pipeline it claims to explain."""
-    import time
-
-    from repro.retrieval.index import FlatIndex
-    from repro.serve import EngineConfig, ServeEngine
-    from repro.serve.session import SessionManager
-
-    dim, num_docs, n_req, max_batch = 64, 2048, 16, 8
-    emb = _unit(rng, num_docs, dim)
-    index = FlatIndex.build(
-        emb, documents=[f"doc-{i}".encode() for i in range(num_docs)])
-    queries = _unit(rng, n_req, dim)
-    cache_cfg = rlwe.CandidateCacheConfig(num_shards=8, admit_threshold=1)
-
-    def run_stream(trace: bool):
-        eng = ServeEngine(
-            index,
-            config=EngineConfig(max_batch=max_batch, max_wait_s=30.0,
-                                cache_config=cache_cfg, trace=trace),
-            sessions=SessionManager(rlwe_params=params,
-                                    deterministic_seeds=True))
-        for t in range(4):
-            eng.open_session(f"bench-{t}", n=dim, N=num_docs, k=4,
-                             radius=0.05, backend="rlwe")
-        for i in range(n_req):
-            eng.submit(f"bench-{i % 4}", queries[i],
-                       key=jax.random.PRNGKey(i))
-        t0 = time.perf_counter()
-        out = eng.drain()
-        wall_us = (time.perf_counter() - t0) * 1e6
-        assert all(r.ok for r in out), "stage-breakdown stream must succeed"
-        tracer = eng.tracer
-        eng.close()
-        return wall_us, tracer
-
-    untraced_us, _ = run_stream(trace=False)       # also the jit warmup
-    traced_us, tracer = run_stream(trace=True)
-    stages = tracer.stage_summary()
-    core = ("perturb", "topk", "encrypt", "score", "decrypt", "finish")
-    stage_sum = sum(stages[s]["total_s"] for s in core if s in stages)
-    dispatch_s = stages["dispatch"]["total_s"]
-    coverage = stage_sum / dispatch_s
-    emit("rlwe/serve_stage_coverage_b8", traced_us, f"{coverage:.2f}x")
-    for s in core:
-        if s in stages:
-            emit(f"rlwe/serve_stage_{s}", stages[s]["total_s"] * 1e6,
-                 f"p99={stages[s]['p99_s'] * 1e6:.0f}us")
-    return {
-        "num_docs": num_docs,
-        "requests": n_req,
-        "max_batch": max_batch,
-        "wall_untraced_us": untraced_us,
-        "wall_traced_us": traced_us,
-        "traced_overhead_ratio": traced_us / untraced_us,
-        "stage_coverage": coverage,
-        "trace_spans": len(tracer.spans()),
-        "trace_dropped": tracer.dropped,
-        "stages": stages,
-    }
-
-
 def _paillier_batch_section(rng) -> dict:
     """Vectorized-Paillier section: the RNS limb-array batch path
     (`repro.crypto.paillier_vec`, fixed-width residue channels +
@@ -717,7 +646,6 @@ def run() -> None:
     results["sharded"] = sharded
 
     results["serve_faults"] = _serve_fault_section(params, rng)
-    results["stage_breakdown"] = _stage_breakdown_section(params, rng)
     results["paillier_batch"] = _paillier_batch_section(rng)
     results["ivf_routing"] = _ivf_routing_section(rng)
     results["ingestion"] = _ingestion_section(params, rng)

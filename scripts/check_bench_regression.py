@@ -21,10 +21,6 @@ and fails if
     exactly 0), more/fewer error results than poisoned lanes, or batch
     occupancy under faults below ``min_occupancy_ratio`` (default 0.9) of
     the fault-free run, or
-  * (stage_breakdown section) the repro.obs stage timeline stopped
-    accounting for the dispatch it claims to explain: a core pipeline
-    stage went missing from a traced serve stream, or the summed stage
-    durations fall outside [0.5, 1.05] of the dispatch wall, or
   * (paillier_batch section — missing section = FAIL) the vectorized
     RNS-limb Paillier batch path is less than ``min_paillier_speedup``
     (default 3.0x) faster than the per-lane object path at batch 8, its
@@ -215,45 +211,6 @@ def _check_serve_faults(section: dict, min_occupancy_ratio: float) -> int:
               f"({section.get('occupancy_faulty'):.3f} vs "
               f"{section.get('occupancy_fault_free'):.3f} at batch "
               f"{section.get('max_batch')})")
-    return failures
-
-
-def _check_stage_breakdown(section: dict, min_coverage: float = 0.5,
-                           max_coverage: float = 1.05) -> int:
-    """Observability gate: the traced serve stream must record every core
-    pipeline stage, and the summed stage durations must reconcile with
-    the dispatch wall they partition.  A JSON without the section fails —
-    the gate must not silently pass after a results-key rename."""
-    if section is None:
-        print("FAIL stage_breakdown: results lack the traced stage-"
-              "breakdown section — the observability gate did not run",
-              file=sys.stderr)
-        return 1
-    failures = 0
-    stages = section.get("stages", {})
-    core = ("queue_wait", "dispatch", "perturb", "topk", "encrypt",
-            "score", "decrypt", "finish")
-    missing = [s for s in core
-               if stages.get(s, {}).get("count", 0) <= 0]
-    if missing:
-        print(f"FAIL stage_breakdown: traced stream recorded no spans for "
-              f"stage(s) {missing} — the timeline lost part of the "
-              f"pipeline", file=sys.stderr)
-        failures += 1
-    else:
-        print(f"ok   stage_breakdown: all {len(core)} core stages present "
-              f"({section.get('trace_spans')} spans, "
-              f"{section.get('trace_dropped')} dropped)")
-    coverage = section.get("stage_coverage")
-    if coverage is None or not (min_coverage <= coverage <= max_coverage):
-        print(f"FAIL stage_breakdown: stage durations cover {coverage}x of "
-              f"the dispatch wall, outside [{min_coverage}, "
-              f"{max_coverage}] — spans no longer reconcile with "
-              f"end-to-end latency", file=sys.stderr)
-        failures += 1
-    else:
-        print(f"ok   stage_breakdown: stage durations cover "
-              f"{coverage:.2f}x of the dispatch wall")
     return failures
 
 
@@ -757,7 +714,6 @@ def main() -> int:
               "JSON); skipping the sharded gates")
     failures += _check_serve_faults(results.get("serve_faults"),
                                     args.min_occupancy_ratio)
-    failures += _check_stage_breakdown(results.get("stage_breakdown"))
     failures += _check_paillier_batch(results.get("paillier_batch"),
                                       args.min_paillier_speedup)
     failures += _check_ivf_routing(results.get("ivf_routing"),

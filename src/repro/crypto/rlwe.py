@@ -164,9 +164,13 @@ class PackedCandidates:
     num_cands: int
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["c0", "c1"],
+                   meta_fields=["n_dim", "num_cands"])
 @dataclasses.dataclass(frozen=True, eq=False)
 class ScoreCiphertexts:
-    """Encrypted inner products: (num_ct, P, N) int32 per component."""
+    """Encrypted inner products: (num_ct, P, N) int32 per component.  A
+    pytree of its two arrays, so ``jax.block_until_ready`` reaches them."""
     c0: jnp.ndarray
     c1: jnp.ndarray
     n_dim: int

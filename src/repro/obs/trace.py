@@ -17,6 +17,14 @@ fixed-capacity ring buffer (oldest dropped first, `dropped` counts them)
 while per-stage `StageHistogram` aggregates are updated on every span, so
 the stage-level p50/p99 profile stays complete even after the ring wraps.
 
+Each `Tracer.span` also opens a ``jax.profiler.TraceAnnotation`` named
+``ANNOTATION_PREFIX + name`` around the block it times, whose metadata is
+the span's validated attrs plus its ``batch_id``/``request_id``: under a
+`jax.profiler` session the stages sit on the profile's host timeline,
+and the redaction contract covers the profile exactly as it covers the
+ring.  `record` and `event` (intervals stamped after the fact, markers)
+open no annotation.
+
 Tracing is off by default — `NULL_TRACER` is a shared no-op sink whose
 `span()` returns a reusable empty context manager, keeping the disabled
 cost to a dict build and an attribute lookup per call site (gated in CI
@@ -33,6 +41,7 @@ from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.obs.histogram import StageHistogram, summarize
 
@@ -72,6 +81,9 @@ ALLOWED_ATTR_KEYS = frozenset({
 })
 
 _MAX_STR = 64        # short labels only; doc text cannot fit a label
+
+# name prefix of the profiler annotation each `Tracer.span` opens
+ANNOTATION_PREFIX = "repro/"
 
 
 def validate_attrs(attrs: dict) -> dict:
@@ -164,10 +176,15 @@ class Tracer:
         """Record a completed interval with explicit timestamps (for
         intervals whose start predates the call, e.g. queue wait measured
         from ``t_enqueue``)."""
+        return self._add(name, t_start, t_end, track, request_id, batch_id,
+                         {**self.common, **validate_attrs(attrs)})
+
+    def _add(self, name: str, t_start: float, t_end: float, track: str,
+             request_id: Optional[int], batch_id: Optional[int],
+             attrs: dict) -> Span:
         span = Span(name=name, track=track, t_start=float(t_start),
                     duration_s=max(float(t_end) - float(t_start), 0.0),
-                    request_id=request_id, batch_id=batch_id,
-                    attrs={**self.common, **validate_attrs(attrs)})
+                    request_id=request_id, batch_id=batch_id, attrs=attrs)
         with self._lock:
             if len(self._spans) == self.capacity:
                 self.dropped += 1
@@ -182,19 +199,24 @@ class Tracer:
     def span(self, name: str, *, track: str = "engine",
              request_id: Optional[int] = None,
              batch_id: Optional[int] = None, **attrs):
-        """Time a block.  If the body raises, the span is still recorded —
-        with the exception *class name* only — and the exception
-        propagates (fault attribution stays visible on the timeline)."""
-        t0 = self.clock()
-        try:
-            yield
-        except Exception as e:
-            self.record(name, t0, self.clock(), track=track,
-                        request_id=request_id, batch_id=batch_id,
-                        error_type=type(e).__name__, **attrs)
-            raise
-        self.record(name, t0, self.clock(), track=track,
-                    request_id=request_id, batch_id=batch_id, **attrs)
+        """Time a block, inside a profiler annotation of the same name.
+        If the body raises, the span is still recorded — with the
+        exception *class name* only — and the exception propagates (fault
+        attribution stays visible on the timeline)."""
+        attrs = {**self.common, **validate_attrs(attrs)}
+        ids = {k: v for k, v in (("batch_id", batch_id),
+                                 ("request_id", request_id))
+               if v is not None}
+        with TraceAnnotation(ANNOTATION_PREFIX + name, **attrs, **ids):
+            t0 = self.clock()
+            try:
+                yield
+            except Exception as e:
+                self._add(name, t0, self.clock(), track, request_id,
+                          batch_id, {**attrs, "error_type": type(e).__name__})
+                raise
+            t1 = self.clock()
+        self._add(name, t0, t1, track, request_id, batch_id, attrs)
 
     def event(self, name: str, *, track: str = "engine",
               request_id: Optional[int] = None,
@@ -295,5 +317,5 @@ class NullTracer:
 
 NULL_TRACER = NullTracer()
 
-__all__ = ["ALLOWED_ATTR_KEYS", "validate_attrs", "Span", "Tracer",
+__all__ = ["ALLOWED_ATTR_KEYS", "ANNOTATION_PREFIX", "validate_attrs", "Span", "Tracer",
            "NullTracer", "NULL_TRACER"]

@@ -989,6 +989,11 @@ class ServeEngine:
                      kprime=kprime, backend=backend):
             cts, bad = _bisect_lanes(score, alive, tracer=tr,
                                      batch_id=bid, stage="score")
+            if tr.enabled:
+                # end the span when the device holds the ciphertexts, so
+                # their device time is read as score's and not decrypt's
+                # (decrypt waits for them next in any case)
+                jax.block_until_ready(cts)
         if bad:
             full_stack.clear()            # stack no longer matches alive
         drop(bad)

@@ -1,13 +1,18 @@
 """repro.obs: span schema redaction, ring bounding, stage histograms,
-Chrome-trace export.  The engine-integration side (stage coverage over a
+Chrome-trace export, profiler annotations.  The engine-integration side (stage coverage over a
 real served stream, admitter-span parenting/overlap) lives in
 tests/test_serve.py next to the engine tests."""
 
+import glob
 import json
 import math
+import os
 
 import numpy as np
 import pytest
+
+import jax
+from jax.profiler import ProfileData
 
 from repro import obs
 from repro.obs.trace import _MAX_STR
@@ -111,6 +116,70 @@ def test_null_tracer_is_inert():
     # even bad attrs are ignored when disabled — no validation cost
     with nt.span("stage", embedding=np.zeros(3)):
         pass
+
+
+# -- profiler annotations ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The ``repro/`` host events of one CPU profiler session in which a
+    tracer and the NULL tracer each time a few spans; returns
+    ({event name: [stats dict, ...]}, the tracer)."""
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    tracer = obs.Tracer(common={"replica": 1})
+    jax.profiler.start_trace(log_dir)
+    try:
+        with tracer.span("x", batch_id=3, lanes=2, backend="rlwe"):
+            pass
+        with tracer.span("finish", request_id=7, batch_id=3, lane=0):
+            pass
+        with pytest.raises(ValueError):
+            with tracer.span("y", doc_ids=17):
+                pass
+        with pytest.raises(RuntimeError):
+            with tracer.span("boom", batch_id=4):
+                raise RuntimeError("secret query payload")
+        with obs.NULL_TRACER.span("null", batch_id=5, lanes=1):
+            pass
+        tracer.record("queue_wait", 0.0, 1.0, batch_id=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(obs.ANNOTATION_PREFIX):
+                    events.setdefault(ev.name, []).append(dict(ev.stats))
+    return events, tracer
+
+
+def test_span_annotates_the_profiler_host_timeline(profiled):
+    events, tracer = profiled
+    (x,) = events["repro/x"]
+    assert x == {"batch_id": 3, "lanes": 2, "backend": "rlwe",
+                 "replica": 1}
+    (fin,) = events["repro/finish"]
+    assert fin["request_id"] == 7 and fin["batch_id"] == 3
+    # the redaction contract covers the profile as it covers the ring
+    allowed = obs.ALLOWED_ATTR_KEYS | {"batch_id", "request_id"}
+    for stats in (s for evs in events.values() for s in evs):
+        assert set(stats) <= allowed
+    # a failing block still closes its annotation and records its span
+    assert len(events["repro/boom"]) == 1
+    assert [s.name for s in tracer.spans()] == [
+        "x", "finish", "boom", "queue_wait"]
+
+
+def test_annotations_only_from_enabled_span(profiled):
+    """NULL_TRACER opens nothing, a span whose attrs break the schema
+    opens nothing, and `record` (an interval stamped afterwards) opens
+    nothing."""
+    events, _ = profiled
+    assert set(events) == {"repro/x", "repro/finish", "repro/boom"}
 
 
 # -- histograms -------------------------------------------------------------

@@ -776,6 +776,52 @@ def test_traced_run_stages_redaction_reconciliation(corpus, tmp_path):
     assert doc["metadata"]["stage_summary"] == eng.tracer.stage_summary()
 
 
+@pytest.mark.parametrize("trace", [True, False])
+def test_score_span_ends_at_device_readiness(corpus, monkeypatch, trace):
+    """Traced, the engine waits for the score ciphertexts inside every
+    ``score`` span, so their device time is read as score's and not as
+    decrypt's; untraced, it never waits."""
+    index, _, queries = corpus
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(time.monotonic())
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    eng, got = _run(index, queries, sequential=False, max_batch=3,
+                    trace=trace)
+    assert len(got) == N_REQ and all(r.ok for r in got)
+    if not trace:
+        assert calls == []
+        return
+    scores = [s for s in eng.tracer.spans() if s.name == "score"]
+    assert len(scores) >= 3                 # 8 requests, batches of <= 3
+    assert len(calls) == len(scores)
+    for s in scores:
+        assert sum(s.t_start <= t <= s.t_end for t in calls) == 1
+
+
+@pytest.mark.parametrize("max_batch", [1, 3, 8])
+def test_one_queue_wait_span_per_completed_request(corpus, max_batch):
+    """Each request's wait in the queue is one ``queue_wait`` span, from
+    its enqueue to the start of its batch's dispatch."""
+    index, _, queries = corpus
+    eng, got = _run(index, queries, sequential=False, max_batch=max_batch,
+                    trace=True)
+    assert len(got) == N_REQ and all(r.ok for r in got)
+    spans = eng.tracer.spans()
+    waits = [s for s in spans if s.name == "queue_wait"]
+    assert sorted(s.request_id for s in waits) == sorted(
+        r.request_id for r in got)
+    dispatches = {s.batch_id: s for s in spans if s.name == "dispatch"}
+    assert len(dispatches) == -(-N_REQ // max_batch)
+    for w in waits:
+        assert w.t_end == pytest.approx(dispatches[w.batch_id].t_start,
+                                        abs=1e-3)
+
+
 def test_sharded_admission_span_parented_and_overlapping_encrypt(corpus):
     """The async shard admitter emits "cache_admit" spans on its own
     "admitter" track, parented (batch_id) to the dispatch that enqueued
