@@ -21,6 +21,10 @@ Default run, one process, in order:
               be the exact numpy top-k, and ids, docs and wire bytes must
               be identical across the three runs.  Device memory (in use, and the
               peak so far) is logged after each step.
+  4. layout   the compiled dense scoring step at the served shapes reads the
+              pool's rows in place: no instruction but a parameter has a
+              result with one row per document (a relayout of the pool
+              would copy all of it on every call).
 
 It fails (exit 1, no result line) unless JAX's first device is a TPU.  The
 last line of stdout is the result:
@@ -114,15 +118,13 @@ def kernels_phase(index, rng) -> None:
           "a kernel disagrees with its XLA reference on the chip")
 
 
-def lowering_phase(index, kprime: int) -> dict:
-    """Kernels carried by the lowered top-k' and scoring steps."""
-    import re
-
+def lower_scoring_step(index, kprime: int):
+    """The dense scoring step (`rlwe._cached_scores`) lowered at the served
+    batch and k' over the index's pool, on the Pallas kernels."""
     import jax
     import jax.numpy as jnp
 
     from repro.crypto import rlwe
-    from repro.kernels.scoretopk import ops as sops
 
     params = rlwe.RlweParams()
     cache = index.candidate_cache(params)
@@ -131,9 +133,21 @@ def lowering_phase(index, kprime: int) -> dict:
     c0 = jax.ShapeDtypeStruct((MAX_BATCH, cache.num_chunks,
                                params.num_primes, params.n_poly), jnp.int32)
     ids = jax.ShapeDtypeStruct((MAX_BATCH, kprime), jnp.int32)
-    score_text = rlwe._cached_scores.lower(
+    return rlwe._cached_scores.lower(
         c0, c0, cache.polys, ids, cache.twiddles, ctxs=params.ctxs, cpt=cpt,
-        pad=pad, use_pallas=True).as_text()
+        pad=pad, use_pallas=True)
+
+
+def lowering_phase(index, kprime: int) -> dict:
+    """Kernels carried by the lowered top-k' and scoring steps."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.scoretopk import ops as sops
+
+    score_text = lower_scoring_step(index, kprime).as_text()
     q = jax.ShapeDtypeStruct((MAX_BATCH, DIM), jnp.float32)
     topk_text = jax.jit(lambda q, e: sops.topk_scores(q, e, kprime)).lower(
         q, index.embeddings).as_text()
@@ -144,6 +158,23 @@ def lowering_phase(index, kprime: int) -> dict:
         tpu_custom_calls=(score_text + topk_text).count("tpu_custom_call"))
     check(all(k in found for k in want),
           f"lowered steps carry {found}, expected {want}")
+
+
+def pool_layout_phase(index) -> None:
+    """No instruction of the compiled dense scoring step but a parameter
+    has a result with ``DOCS`` rows: the gather reads the pool in place."""
+    import re
+
+    text = lower_scoring_step(index, KPRIME).compile().as_text()
+    pool_sized = re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = (\w+\[{DOCS},[^\]]*\]\S*) (\S+)\(",
+        text, re.M)
+    log(pool_sized_instructions=[
+        {"name": name, "result": result, "op": op}
+        for name, result, op in pool_sized])
+    ops = {op for _, _, op in pool_sized}
+    check(ops == {"parameter"},
+          f"the compiled dense scoring step makes pool-sized arrays: {ops}")
 
 
 def log_memory(dev, after: str) -> None:
@@ -223,6 +254,7 @@ def serve_phase(seed: int, dev) -> None:
     log(parity="pallas_cold == pallas_warm == xla (ids, docs, wire bytes)",
         peak_bytes_in_use=stats.get("peak_bytes_in_use"),
         bytes_limit=stats.get("bytes_limit"))
+    pool_layout_phase(index)
 
 
 def paillier_phase(seed: int) -> None:

@@ -337,11 +337,17 @@ def params_key(params: RlweParams) -> tuple:
 class CandidateCache:
     """Per-document NTT-domain plaintexts, packed once at index-build time.
 
-    ``polys[d, c]`` holds document d's chunk c reverse-packed at slot 0
-    (p[chunk-1-j] = seg[j]) and forward-NTT'd per prime: (num_docs, chunks,
-    P, N) int32 — 4*P*N bytes per chunk per document (48 KiB/doc/chunk at
-    the default N=4096, P=3).  Realizing document d at slot s of a result
-    ciphertext is a pointwise multiply by ``twiddles[:, s]``, the NTT-domain
+    Row ``polys[d]`` holds document d's chunks, each reverse-packed at slot
+    0 (p[chunk-1-j] = seg[j]) and forward-NTT'd per prime, flattened in
+    (chunk, prime, coefficient) order: (num_docs, chunks*P*N) int32 — 4*P*N
+    bytes per chunk per document (48 KiB/doc/chunk at the default N=4096,
+    P=3).  One row per document is the layout the TPU gather reads in place
+    (an embedding lookup); a (num_docs, chunks, P, N) device array makes
+    XLA relayout the whole pool on every scoring call.  `host_pool` gives
+    the (num_docs, chunks, P, N) view.
+
+    Realizing document d at slot s of a result ciphertext is a pointwise
+    multiply by ``twiddles[:, s]``, the NTT-domain
     diagonal of the monomial X^{s*stride}: the slot-0 support [0, chunk)
     never crosses X^N + 1 for s < cands_per_ct, so X^{s*stride} * base is
     exactly the polynomial the cold packer would have built, and the NTT is
@@ -352,7 +358,7 @@ class CandidateCache:
     (the build-once/serve-many contract is per (index, params-value) pair).
     """
     params: RlweParams
-    polys: jnp.ndarray             # (num_docs, chunks, P, N) int32, NTT domain
+    polys: jnp.ndarray             # (num_docs, chunks*P*N) int32, NTT domain
     twiddles: jnp.ndarray          # (P, cands_per_ct, N) int32, NTT(X^{s*stride})
     n_dim: int
     num_docs: int
@@ -365,15 +371,19 @@ class CandidateCache:
         return int(self.polys.size) * 4
 
     def host_pool(self) -> np.ndarray:
-        """Host view/copy of the packed pool, memoized on first use so every
-        sharded re-view (`shard_candidate_cache`) shares ONE host array no
-        matter how many configs consume it — and dense-only callers never
-        pay for it.  Zero-copy on the CPU backend; one D2H on accelerators.
+        """Host view/copy of the packed pool as (num_docs, chunks, P, N),
+        memoized on first use so every sharded re-view
+        (`shard_candidate_cache`) shares ONE host array no matter how many
+        configs consume it — and dense-only callers never pay for it.
+        Zero-copy on the CPU backend; one D2H on accelerators.
         """
         pool = self.__dict__.get("_host_pool")
         if pool is None:
             # frozen dataclass: memoize via __dict__ (cached_property style)
-            pool = self.__dict__["_host_pool"] = np.asarray(self.polys)
+            pool = self.__dict__["_host_pool"] = np.asarray(
+                self.polys).reshape(self.num_docs, self.num_chunks,
+                                    self.params.num_primes,
+                                    self.params.n_poly)
         return pool
 
     def check_compatible(self, params: RlweParams, n_dim=None) -> None:
@@ -430,6 +440,21 @@ def _slot_twiddles(params: RlweParams, n_dim: int) -> jnp.ndarray:
     ])                                                    # (P, cpt, N)
 
 
+def _dense_cache(params: RlweParams, pool: np.ndarray, n_dim: int,
+                 twiddles=None) -> CandidateCache:
+    """The dense cache over a host pool (num_docs, chunks, P, N): one
+    host->device copy of the pool as rows (num_docs, chunks*P*N), a free
+    reshape of the host array."""
+    chunks, stride, cpt = _cache_geometry(params, n_dim)
+    if twiddles is None:
+        twiddles = _slot_twiddles(params, n_dim)
+    return CandidateCache(params=params,
+                          polys=jnp.asarray(pool.reshape(pool.shape[0], -1)),
+                          twiddles=twiddles, n_dim=n_dim,
+                          num_docs=pool.shape[0], stride=stride,
+                          cands_per_ct=cpt, num_chunks=chunks)
+
+
 def build_candidate_cache(params: RlweParams,
                           embeddings: np.ndarray) -> CandidateCache:
     """Precompute the NTT-domain plaintexts of every document (slot 0) plus
@@ -438,13 +463,7 @@ def build_candidate_cache(params: RlweParams,
     workload touches only per-request data.  The whole pool lives dense in
     device memory — at corpus scale use `build_sharded_candidate_cache`."""
     emb = np.asarray(embeddings)
-    num_docs, n_dim = emb.shape
-    chunks, stride, cpt = _cache_geometry(params, n_dim)
-    pool = _pack_corpus_ntt(params, emb)
-    return CandidateCache(params=params, polys=jnp.asarray(pool),
-                          twiddles=_slot_twiddles(params, n_dim),
-                          n_dim=n_dim, num_docs=num_docs, stride=stride,
-                          cands_per_ct=cpt, num_chunks=chunks)
+    return _dense_cache(params, _pack_corpus_ntt(params, emb), emb.shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -1143,11 +1162,7 @@ def densify_candidate_cache(cache: ShardedCandidateCache) -> CandidateCache:
     """Dense device-resident view of a sharded cache's pool (one
     host->device copy, no re-pack; the host pool stays shared)."""
     pool = cache.host_pool()       # includes any ingested tail shards
-    dense = CandidateCache(
-        params=cache.params, polys=jnp.asarray(pool),
-        twiddles=cache.twiddles, n_dim=cache.n_dim,
-        num_docs=pool.shape[0], stride=cache.stride,
-        cands_per_ct=cache.cands_per_ct, num_chunks=cache.num_chunks)
+    dense = _dense_cache(cache.params, pool, cache.n_dim, cache.twiddles)
     dense.__dict__["_host_pool"] = pool         # keep the pool shared
     return dense
 
@@ -1183,13 +1198,16 @@ def _cached_scores(c0, c1, polys, ids, twiddles, ctxs, cpt, pad, use_pallas):
     """Whole-batch dense-cache scoring in ONE compiled call: the cache
     gather, last-ct zero padding, and the per-prime loop all live in a
     single trace, so the full gather -> rotate -> Hadamard -> slot/chunk
-    mod-sum -> iNTT pipeline runs without host round-trips.  ``use_pallas``
-    is static: the same trace routes through the fused Pallas kernel or the
-    jitted XLA references (one layout/padding implementation for both, so
-    the bit-identity contract holds by construction)."""
+    mod-sum -> iNTT pipeline runs without host round-trips.  ``polys`` is
+    the pool as rows (num_docs, chunks*P*N), gathered in place; the k'
+    gathered rows are unflattened to the query's (chunks, P, N).
+    ``use_pallas`` is static: the same trace routes through the fused
+    Pallas kernel or the jitted XLA references (one layout/padding
+    implementation for both, so the bit-identity contract holds by
+    construction)."""
     bsz, num_cands = ids.shape
-    g = jnp.take(polys, ids.reshape(-1), axis=0)
-    g = g.reshape((bsz, num_cands) + polys.shape[1:])   # (B, nc, chunks, P, N)
+    g = jnp.take(polys, ids.reshape(-1), axis=0)        # (B*nc, chunks*P*N)
+    g = g.reshape((bsz, num_cands) + c0.shape[1:])      # (B, nc, chunks, P, N)
     return _scores_pipeline(c0, c1, g, twiddles, ctxs, cpt, pad, use_pallas)
 
 

@@ -150,3 +150,65 @@ def test_monomial_rotation_identity_hypothesis():
         np.testing.assert_array_equal(lhs, rhs)
 
     prop()
+
+
+# The served geometry in miniature: one chunk per document and 4 candidates
+# per result ciphertext, as at N=4096 and 768 dims, and k' = 161, which
+# leaves 3 empty slots in the last of 41 result ciphertexts.
+SERVED_PARAMS = rlwe.RlweParams(n_poly=1024, chunk=256)
+SERVED_DIM, SERVED_KPRIME, SERVED_DOCS = 192, 161, 300
+
+
+@pytest.fixture(scope="module")
+def served():
+    rng = np.random.default_rng(161)
+    docs = _unit(rng, SERVED_DOCS, SERVED_DIM)
+    cache = rlwe.build_candidate_cache(SERVED_PARAMS, docs)
+    assert (cache.cands_per_ct, cache.num_chunks) == (4, 1)
+    sk = rlwe.keygen(SERVED_PARAMS, rng)
+    q_cts = [rlwe.encrypt_query(sk, q, rng)
+             for q in _unit(rng, 8, SERVED_DIM)]
+    return docs, cache, q_cts
+
+
+@pytest.mark.parametrize("bsz", [1, 3, 8])
+def test_pool_rows_score_like_fresh_packing(served, bsz):
+    """The dense pool stored as one row per document scores bit-identically
+    to fresh packing, also through a sharded re-view of that pool: the same
+    ciphertexts, so the same decrypted scores and wire bytes."""
+    docs, cache, q_cts = served
+    rng = np.random.default_rng(bsz)
+    ids = rng.integers(0, SERVED_DOCS, size=(bsz, SERVED_KPRIME))
+    packed = rlwe.pack_candidates_batch(SERVED_PARAMS, docs[ids])
+    fresh = rlwe.encrypted_scores_batch(
+        SERVED_PARAMS, q_cts[:bsz], packed, SERVED_KPRIME, SERVED_DIM,
+        use_pallas=False)
+    cached = rlwe.encrypted_scores_cached_batch(
+        SERVED_PARAMS, q_cts[:bsz], cache, ids, use_pallas=False)
+    sharded = rlwe.encrypted_scores_cached_batch(
+        SERVED_PARAMS, q_cts[:bsz],
+        rlwe.shard_candidate_cache(
+            cache, rlwe.CandidateCacheConfig(shard_docs=64)),
+        ids, use_pallas=False)
+    assert cached.c0.shape[1] == 41
+    for got in (cached, sharded):
+        for lane, want in zip(got.lanes(), fresh):
+            np.testing.assert_array_equal(np.asarray(lane.c0),
+                                          np.asarray(want.c0))
+            np.testing.assert_array_equal(np.asarray(lane.c1),
+                                          np.asarray(want.c1))
+
+
+def test_host_pool_is_the_packed_pool(served):
+    """Built or densified, the dense cache holds the packed pool as rows on
+    the device, and `host_pool` gives it back as (docs, chunks, P, N)."""
+    docs, cache, _ = served
+    pool = rlwe._pack_corpus_ntt(SERVED_PARAMS, docs)
+    dense = rlwe.densify_candidate_cache(rlwe.build_sharded_candidate_cache(
+        SERVED_PARAMS, docs, config=rlwe.CandidateCacheConfig(num_shards=3)))
+    for c in (cache, dense):
+        assert c.polys.shape == (SERVED_DOCS, pool[0].size)
+        assert c.nbytes == pool.nbytes
+        np.testing.assert_array_equal(c.host_pool(), pool)
+        np.testing.assert_array_equal(np.asarray(c.polys),
+                                      pool.reshape(SERVED_DOCS, -1))

@@ -45,6 +45,11 @@ def setup(request, sk):
     return n_dim, docs, dense, q_cts
 
 
+def _device_pool(dense):
+    """The dense cache's device rows, unflattened to (docs, chunks, P, N)."""
+    return np.asarray(dense.polys).reshape(dense.host_pool().shape)
+
+
 def _sharded(dense, **kw):
     kw.setdefault("shard_docs", SHARD_DOCS)
     return rlwe.shard_candidate_cache(dense,
@@ -63,7 +68,7 @@ def test_shard_geometry_and_pool_accounting(setup):
     assert sh.pool_nbytes == dense.nbytes
     np.testing.assert_array_equal(
         np.concatenate([np.asarray(s) for s in sh.shards]),
-        np.asarray(dense.polys))
+        dense.host_pool())
     assert sh.shard_of(0) == 0 and sh.shard_of(NUM_DOCS - 1) == 4
     # nothing resident before the first gather
     assert sh.resident_bytes == 0 and sh.resident_shards == ()
@@ -112,8 +117,7 @@ def test_fused_intt_kernel_bit_identical_to_staged(setup):
     num_ct = -(-KPRIME // cpt)
     pad = num_ct * cpt - KPRIME
     import jax.numpy as jnp
-    g = np.asarray(dense.polys)[ids.reshape(-1)].reshape(
-        (2, KPRIME) + np.asarray(dense.polys).shape[1:])
+    g = dense.host_pool()[ids]              # (2, KPRIME, chunks, P, N)
     if pad:
         g = np.concatenate(
             [g, np.zeros((2, pad) + g.shape[2:], np.int32)], axis=1)
@@ -206,8 +210,7 @@ def test_gather_rows_match_pool(setup):
     rng = np.random.default_rng(3)
     ids = rng.integers(0, NUM_DOCS, size=(2, 5))
     g = np.asarray(sh.gather(ids))
-    pool = np.asarray(dense.polys)
-    np.testing.assert_array_equal(g, pool[ids])
+    np.testing.assert_array_equal(g, dense.host_pool()[ids])
 
 
 def test_sharded_scores_decrypt_like_cold(setup, sk):
@@ -260,7 +263,7 @@ def test_index_memoizes_per_params_and_config(setup):
     b = index.candidate_cache(PARAMS, rlwe.CandidateCacheConfig(shard_docs=4))
     assert b.pool is a.pool
     assert dense.host_pool() is a.pool
-    np.testing.assert_array_equal(np.asarray(dense.polys), a.pool)
+    np.testing.assert_array_equal(_device_pool(dense), a.pool)
 
 
 def test_admission_never_exceeds_budget_transiently(setup):
@@ -308,8 +311,7 @@ def test_densify_roundtrip(setup):
     n_dim, docs, dense, q_cts = setup
     sh = _sharded(dense)
     back = rlwe.densify_candidate_cache(sh)
-    np.testing.assert_array_equal(np.asarray(back.polys),
-                                  np.asarray(dense.polys))
+    np.testing.assert_array_equal(_device_pool(back), dense.host_pool())
     resharded = rlwe.shard_candidate_cache(sh,
                                            rlwe.CandidateCacheConfig(
                                                shard_docs=4))

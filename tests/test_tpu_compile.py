@@ -123,7 +123,9 @@ def test_reference_scan_fits_chip(one_chip, num_docs):
 def test_cached_scores_step_compiles(one_chip, monkeypatch):
     """The whole dense-cache scoring step (gather -> per prime: 2 query
     NTTs + fused rotate/Hadamard/iNTT) over a 10^5-doc pool, as served:
-    nine kernels, and the program fits one chip's HBM."""
+    nine kernels, the program fits one chip's HBM, and the gather reads the
+    pool's rows in place: no instruction but the parameter has a result
+    with one row per document (a relayout of the pool would)."""
     # the step picks interpret mode from the platform, which is the CPU
     # here; steer it to the compiled kernels this compile is for
     monkeypatch.setattr(ntt_ops, "_interpret", lambda: False)
@@ -134,7 +136,7 @@ def test_cached_scores_step_compiles(one_chip, monkeypatch):
             c0, c1, polys, ids, tw, PARAMS.ctxs, CPT, pad, True),
         _sds(one_chip, (8, chunks, nprimes, N)),
         _sds(one_chip, (8, chunks, nprimes, N)),
-        _sds(one_chip, (NUM_DOCS, chunks, nprimes, N)),
+        _sds(one_chip, (NUM_DOCS, chunks * nprimes * N)),  # the pool rows
         _sds(one_chip, (8, KPRIME)),
         _sds(one_chip, (nprimes, CPT, N)))
     # the lowered program names each kernel once per prime; the compiled
@@ -145,3 +147,7 @@ def test_cached_scores_step_compiles(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert np.int64(NUM_DOCS) * chunks * nprimes * N * 4 <= used < HBM_BYTES
+    pool_sized = re.findall(
+        rf"^\s*(?:ROOT )?%\S+ = \w+\[{NUM_DOCS},.*?\s(\S+)\(",
+        compiled.as_text(), re.M)
+    assert pool_sized and set(pool_sized) == {"parameter"}
