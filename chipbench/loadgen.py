@@ -17,6 +17,22 @@ Every seed gets the same work: the open loop's inter-arrival gaps are the
 same stratified set of exponential quantiles in a seed-drawn order, and
 tenant counts are fixed by the Zipf weights (largest remainder) in a
 seed-drawn order.  Only which corpus row each query lies near is free.
+
+Optional keys of a mix, each drawn after the draws above so that a mix
+without them gives the same schedule:
+
+- ``burst_factor``, ``burst_period_s``, ``burst_duty`` (open loop): the
+  rate is ``burst_factor`` times ``rate_rps`` for the first ``burst_duty``
+  of every period and lower for the rest, so the mean stays ``rate_rps``;
+  the stratified arrival times are warped through the inverse of the
+  cumulative rate (`burst_warp`), so the count and the work stay the same;
+- ``topic_zipf`` (a corpus with topics): each query's topic is drawn
+  Zipf(``topic_zipf``) over topic ranks (counts fixed by largest
+  remainder, 0 for the uniform-topic control), then its row uniformly
+  within the topic;
+- ``hot_shift_s``: every this many seconds a seed-drawn permutation
+  re-ranks the topics, so the hot set moves (its top topic always
+  changes); a closed loop shifts by request index at ``nominal_rps``.
 """
 
 from __future__ import annotations
@@ -29,7 +45,11 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from chipbench.spec import SpecError
+
 OPEN, CLOSED = "open", "closed"
+BURST_KEYS = ("burst_factor", "burst_period_s", "burst_duty")
+TOPIC_KEYS = ("topic_zipf", "hot_shift_s")
 GRACE_S = 60.0           # how long past the window the harness waits
 IDLE_SLEEP_S = 0.0005    # poll period of an idle engine loop
 
@@ -47,10 +67,11 @@ class Schedule:
         return len(self.tenant)
 
 
-def tenant_counts(n: int, tenants: int, zipf_s: float) -> np.ndarray:
-    """Requests per tenant: Zipf(s) weights over ``tenants`` ranks, rounded
-    by largest remainder so the counts sum to ``n``."""
-    w = 1.0 / np.arange(1, tenants + 1) ** zipf_s
+def zipf_counts(n: int, ranks: int, zipf_s: float) -> np.ndarray:
+    """``n`` split over ``ranks`` by Zipf(s) weights (requests per tenant or
+    per topic rank, documents per topic), rounded by largest remainder so
+    the counts sum to ``n``."""
+    w = 1.0 / np.arange(1, ranks + 1) ** zipf_s
     exact = n * w / w.sum()
     counts = np.floor(exact).astype(np.int64)
     rest = n - int(counts.sum())
@@ -65,23 +86,97 @@ def stratified_gaps(n: int, rate: float) -> np.ndarray:
     return -np.log1p(-u) / rate
 
 
-def make_schedule(mix: dict, seconds: float, n_rows: int,
-                  rng: np.random.Generator) -> Schedule:
-    """The requests of one run of ``mix`` over a ``seconds`` window."""
+def check_mix(mix: dict, has_topics: bool) -> None:
+    """Refuse a mix whose optional keys do not fit its loop or corpus."""
     loop = mix["loop"]
-    if loop == OPEN:
+    if loop not in (OPEN, CLOSED):
+        raise SpecError(f"unknown loop {loop!r} in traffic mix")
+    bursts = [k for k in BURST_KEYS if k in mix]
+    if bursts:
+        if loop != OPEN or len(bursts) != len(BURST_KEYS):
+            raise SpecError(f"bursts need an open loop and all of "
+                            f"{BURST_KEYS}, got {bursts} ({loop})")
+        f, p, d = (float(mix[k]) for k in BURST_KEYS)
+        if not (f >= 1 and p > 0 and 0 < d < 1 and f * d <= 1):
+            raise SpecError(f"bursts need burst_factor >= 1, period > 0, "
+                            f"0 < duty < 1 and factor * duty <= 1: "
+                            f"{f}, {p}, {d}")
+    topical = [k for k in TOPIC_KEYS if k in mix]
+    if topical and not has_topics:
+        raise SpecError(f"mix keys {topical} need a configuration whose "
+                        f"corpus block has topics")
+    if "hot_shift_s" in mix:
+        if "topic_zipf" not in mix or not float(mix["hot_shift_s"]) > 0:
+            raise SpecError("hot_shift_s needs topic_zipf and is > 0")
+        if loop == CLOSED and not float(mix.get("nominal_rps", 0)) > 0:
+            raise SpecError("a closed loop's hot_shift_s needs nominal_rps")
+
+
+def burst_warp(tau: np.ndarray, seconds: float, factor: float,
+               period: float, duty: float) -> np.ndarray:
+    """Arrival times ``tau`` of a steady rate over ``[0, seconds)`` mapped
+    onto a rate ``factor`` times the mean in the first ``duty`` of each
+    ``period`` and ``(1 - duty factor) / (1 - duty)`` times it in the rest:
+    the inverse of the cumulative rate (over the mean), stretched so that
+    what was in the window stays in it."""
+    low = (1.0 - duty * factor) / (1.0 - duty)
+    on = duty * period                      # the burst's length
+    head = factor * on                      # its share of a period's work
+
+    def cum(t):
+        k, ph = np.divmod(t, period)
+        return k * period + np.where(ph < on, factor * ph,
+                                     head + low * (ph - on))
+
+    k, g = np.divmod(tau * (cum(seconds) / seconds), period)
+    tail = np.divide(g - head, low, out=np.zeros_like(g), where=g >= head)
+    return k * period + np.where(g < head, g / factor, on + tail)
+
+
+def topic_of(mix: dict, due_s: Optional[np.ndarray], n: int, topics: int,
+             rng: np.random.Generator) -> np.ndarray:
+    """Each request's topic: its Zipf rank (fixed counts, seed-drawn
+    order) through the ranking of its hot-set epoch."""
+    rank = rng.permutation(np.repeat(
+        np.arange(topics), zipf_counts(n, topics, mix["topic_zipf"])))
+    shift = mix.get("hot_shift_s")
+    if shift is None:
+        epoch = np.zeros(n, np.int64)
+    elif due_s is not None:
+        epoch = np.floor(due_s / shift).astype(np.int64)
+    else:
+        epoch = np.arange(n) // max(1, round(mix["nominal_rps"] * shift))
+    ranking = np.empty((int(epoch.max()) + 1, topics), np.int64)
+    for e in range(len(ranking)):
+        ranking[e] = rng.permutation(topics)
+        if e and topics > 1 and ranking[e, 0] == ranking[e - 1, 0]:
+            ranking[e, [0, 1]] = ranking[e, [1, 0]]
+    return ranking[epoch, rank]
+
+
+def make_schedule(mix: dict, seconds: float, n_rows: int,
+                  rng: np.random.Generator,
+                  topics: Optional[np.ndarray] = None) -> Schedule:
+    """The requests of one run of ``mix`` over a ``seconds`` window;
+    ``topics`` are the corpus's topic bounds (`chipbench.corpus`), if it
+    has topics."""
+    check_mix(mix, topics is not None)
+    if mix["loop"] == OPEN:
         n = max(1, round(mix["rate_rps"] * seconds))
         gaps = rng.permutation(stratified_gaps(n, mix["rate_rps"]))
         due = np.cumsum(gaps)       # sum < n / rate: all due in the window
-    elif loop == CLOSED:
+    else:
         n = int(mix["pool"])
         due = None
-    else:
-        raise ValueError(f"unknown loop {loop!r} in traffic mix")
     tenant = rng.permutation(np.repeat(
         np.arange(mix["tenants"]),
-        tenant_counts(n, mix["tenants"], mix["tenant_zipf"])))
+        zipf_counts(n, mix["tenants"], mix["tenant_zipf"])))
     row = rng.integers(0, n_rows, size=n)
+    if "burst_factor" in mix:
+        due = burst_warp(due, seconds, *(float(mix[k]) for k in BURST_KEYS))
+    if "topic_zipf" in mix:
+        topic = topic_of(mix, due, n, len(topics) - 1, rng)
+        row = rng.integers(topics[topic], topics[topic + 1])
     return Schedule(due_s=due, tenant=tenant, row=row)
 
 

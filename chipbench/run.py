@@ -148,6 +148,7 @@ def set_up(cell_name: str, seed: int, *, root: Path = ROOT,
     cell = spec.find_cell(bench, cell_name)
     cfg = spec.load_config(bench, cell["config"], root)
     mix = spec.load_traffic(cell["traffic"], root / "chipbench")
+    loadgen.check_mix(mix, "topics" in cfg.get("corpus", {}))
     dev = device_info(cell["chips"], require_tpu)
 
     from repro.launch.serve import enable_compile_cache
@@ -178,7 +179,8 @@ def play(system, mix: dict, seconds: float, engine,
 
     s = system.seeds if stream_seed is None else corpus.seeds(stream_seed)
     sched = loadgen.make_schedule(mix, seconds, system.cfg["n_docs"],
-                                  np.random.default_rng(s["traffic"]))
+                                  np.random.default_rng(s["traffic"]),
+                                  system.topics)
     queries = corpus.queries_near(np.random.default_rng(s["queries"]),
                                   system.emb, sched.row, mix["query_jitter"])
     keys = serving.noise_keys(np.random.default_rng(s["keys"]), len(sched))
@@ -236,6 +238,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
             opts.host_tracer_level = 1
             jax.profiler.start_trace(tmp.name, profiler_options=opts)
         setup_s = time.monotonic() - t_start
+        cache = {"before": system.cache_stats()}
         su.events.start()
         pauses.active = True
         try:
@@ -246,12 +249,13 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
             if traced:
                 t_stop = time.monotonic()
                 jax.profiler.stop_trace()
+        cache["after"] = system.cache_stats()
         log(window={"compiles": su.events.count(COMPILE_EVENT),
                     "traces": su.events.count(TRACE_EVENT),
                     "seconds": seconds, "requests": int(len(lg.attempted())),
                     "gave_up": lg.gave_up,
                     "longest_step_s": lg.longest_step_s,
-                    "gc": pauses.summary()})
+                    "gc": pauses.summary(), "cache": cache})
         mem = [d.memory_stats() or {} for d in jax.devices()]
         peak = max(m.get("peak_bytes_in_use", 0) for m in mem)
         summary = None
@@ -267,7 +271,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
     engine.close()
     records, batch_sizes = engine.records, engine.batch_sizes
     del engine
-    system.index = system.sessions = None     # free the device state
+    system.index = system.sessions = system.cache = None   # device state
 
     t_ref = time.monotonic()
     numbers = reference.compare(
@@ -289,7 +293,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool, *,
     if traced:
         run = {"loop": mix["loop"], "log": lg, "spans": spans,
                "trace": summary, "batch_sizes": batch_sizes,
-               "occupancy": occupancy, "config": cfg,
+               "occupancy": occupancy, "config": cfg, "cache": cache,
                "kprime": system.plan.kprime, "peaks": su.peaks}
         metrics = {}
         for m in layers:

@@ -7,6 +7,13 @@ does everything a deployment does before serving: the index, the candidate
 pool where the configuration serves from it, one session (and key) per
 tenant, and a warm-up of every batch shape 1..max_batch.
 
+The configuration's ``candidates`` picks the re-rank's candidate path:
+``dense_cache`` (the whole NTT-domain pool on the device),
+``pack_per_request`` (no pool) or ``sharded_cache`` (the host pool in
+shards, hot shards admitted to a device budget), whose ``cache`` block
+holds the fields of `repro.crypto.rlwe.CandidateCacheConfig` by name.
+Nothing else of `EngineConfig` is reachable from a file.
+
 `RecordingEngine` is the engine with two taps that copy what the timed path
 produced for each request (its perturbed query and top-k' candidate ids,
 and the scores it decrypted) for the comparison after the window.
@@ -15,6 +22,7 @@ and the scores it decrypted) for the comparison after the window.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
 import time
 from typing import Dict, List, Optional
@@ -30,9 +38,13 @@ from repro.serve import EngineConfig, ServeEngine
 from repro.serve.session import SessionManager
 
 from chipbench import corpus
+from chipbench.spec import SpecError
 from chipbench.trace import ANNOTATION_PREFIX
 
 DENSE_CACHE, PACK_PER_REQUEST = "dense_cache", "pack_per_request"
+SHARDED_CACHE = "sharded_cache"
+# comparison and legacy modes of the sharded cache, not deployments
+FIXED_CACHE_FIELDS = ("async_admission", "pin_on_access")
 
 
 class AnnotatingTracer(obs.Tracer):
@@ -102,13 +114,36 @@ def rlwe_params(cfg: dict) -> rlwe.RlweParams:
         eta=c["eta"], chunk=c["chunk"])
 
 
+def cache_config(block: dict) -> rlwe.CandidateCacheConfig:
+    """The sharded cache's knobs from a configuration's ``cache`` block,
+    field by field."""
+    fields = {f.name for f in dataclasses.fields(rlwe.CandidateCacheConfig)}
+    unknown = sorted(set(block) - fields)
+    if unknown:
+        raise SpecError(f"cache block keys {unknown} are not fields of "
+                        f"CandidateCacheConfig")
+    fixed = [k for k in FIXED_CACHE_FIELDS if block.get(k, True) is not True]
+    if fixed:
+        raise SpecError(f"cache block sets {fixed} off: a comparison or "
+                        f"legacy mode, not a deployment")
+    try:
+        return rlwe.CandidateCacheConfig(**block)
+    except ValueError as e:
+        raise SpecError(f"cache block: {e}") from e
+
+
 def engine_config(cfg: dict) -> EngineConfig:
     cands = cfg["candidates"]
-    if cands not in (DENSE_CACHE, PACK_PER_REQUEST):
-        raise ValueError(f"unknown candidates mode {cands!r}")
-    return EngineConfig(max_batch=cfg["max_batch"],
-                        max_wait_s=cfg["max_wait_ms"] / 1e3,
-                        use_candidate_cache=cands == DENSE_CACHE)
+    if cands not in (DENSE_CACHE, PACK_PER_REQUEST, SHARDED_CACHE):
+        raise SpecError(f"unknown candidates mode {cands!r}")
+    if ("cache" in cfg) != (cands == SHARDED_CACHE):
+        raise SpecError(f"a cache block goes with candidates "
+                        f"{SHARDED_CACHE!r} and only with it")
+    return EngineConfig(
+        max_batch=cfg["max_batch"], max_wait_s=cfg["max_wait_ms"] / 1e3,
+        use_candidate_cache=cands != PACK_PER_REQUEST,
+        cache_config=(cache_config(cfg["cache"])
+                      if cands == SHARDED_CACHE else None))
 
 
 class System:
@@ -120,9 +155,11 @@ class System:
         self.tenant_names = [f"tenant-{t}" for t in range(tenants)]
         self.phases: Dict[str, float] = {}    # set-up phase -> seconds
         self.warm_batches_s: List[float] = []  # warm-up batches of 1, 2, ...
+        self.ecfg = engine_config(cfg)
         t = time.monotonic()
-        rng = np.random.default_rng(self.seeds["corpus"])
-        self.emb = corpus.unit_rows(rng, cfg["n_docs"], cfg["dim"])
+        # topics: the corpus's topic bounds, None where it has no topics
+        self.emb, self.topics = corpus.embeddings(
+            np.random.default_rng(self.seeds["corpus"]), cfg)
         self.docs = corpus.payloads(np.random.default_rng(self.seeds["docs"]),
                                     cfg["n_docs"], cfg["doc_bytes"])
         t = self._phase("data", t)
@@ -133,9 +170,13 @@ class System:
         self.index.embeddings.block_until_ready()
         t = self._phase("index", t)
         self.params = rlwe_params(cfg)
-        self.ecfg = engine_config(cfg)
+        self.cache = None
         if self.ecfg.use_candidate_cache:
-            self.index.candidate_cache(self.params).polys.block_until_ready()
+            self.cache = self.index.candidate_cache(self.params,
+                                                    self.ecfg.cache_config)
+            jax.block_until_ready(self.cache.twiddles
+                                  if self.ecfg.cache_config else
+                                  self.cache.polys)
             t = self._phase("pool", t)
         self.sessions = SessionManager(rlwe_params=self.params)
         for i, name in enumerate(self.tenant_names):
@@ -176,6 +217,14 @@ class System:
                                        f"{bad[0].error}")
                 self.warm_batches_s.append(time.monotonic() - t_batch)
         self._phase("warm", t)
+
+    def cache_stats(self) -> Optional[dict]:
+        """The sharded cache's counters now (None for another path)."""
+        if self.ecfg.cache_config is None:
+            return None
+        stats = self.cache.stats()
+        stats["resident_shards"] = list(stats["resident_shards"])
+        return stats
 
     def engine(self, tracer=None) -> RecordingEngine:
         eng = RecordingEngine(self.index, config=self.ecfg,

@@ -77,7 +77,7 @@ def test_same_seed_same_schedule_and_same_work_across_seeds():
 
 
 def test_tenant_counts_are_zipf():
-    counts = loadgen.tenant_counts(1000, 8, 0.99)
+    counts = loadgen.zipf_counts(1000, 8, 0.99)
     assert counts.sum() == 1000
     assert list(counts) == sorted(counts, reverse=True)
     assert counts[0] / counts[1] == pytest.approx(2 ** 0.99, rel=0.05)

@@ -80,3 +80,20 @@ def test_no_span_no_value(name):
 def test_decrypt_without_its_spans_is_no_value():
     assert read("stage_ms.decrypt", [span("score", 101.0, 0.01,
                                           lanes=2)]) is None
+
+
+@pytest.mark.parametrize("occupancy, want", [(0.875, 87.5), (1.0, 100.0),
+                                             (None, None)])
+def test_batch_occupancy_is_the_engines_lane_fill(occupancy, want):
+    # 7 of 8 lanes filled on average; None before any batch completed
+    assert spec.load_reader("batch_occupancy")(
+        {"occupancy": occupancy}) == want
+
+
+def test_closed_idle_share_reads_as_the_open_one():
+    from chipbench.trace import Summary
+
+    trace = Summary(window_s=10.0, busy_s=0.6, devices=1, kernel_s={},
+                    kernel_calls={}, top_ops=[], idle_gaps=[], ops=3)
+    for name in ("device_idle_share", "device_idle_share.closed"):
+        assert spec.load_reader(name)({"trace": trace}) == pytest.approx(94.0)
